@@ -1,0 +1,87 @@
+"""Random weights drawn from the seed, on the device, in one program.
+
+The layout is the reference's (`param_shapes` of the architecture),
+which is also the layout the program takes; `check_layout` holds the
+two to each other before a run.  Every leaf has a key of its own,
+folded from the seed and the leaf's index, so a leaf does not depend on
+the others.  Scales: matrices 1/sqrt(fan-in), embeddings and biases
+0.02, norm scales 1 + 0.1 N(0, 1), so that no parameter is a constant
+that would hide a term left out.
+"""
+from __future__ import annotations
+
+import functools
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from bench.reference.model import arch
+
+F32 = jnp.float32
+
+
+def seed_key(seed: int) -> np.ndarray:
+    """A raw threefry key for any seed up to 64 bits."""
+    seed = int(seed) & (2 ** 64 - 1)
+    return np.array([seed >> 32, seed & 0xFFFFFFFF], np.uint32)
+
+
+def _paths(tree, prefix=()):
+    for k in sorted(tree):
+        v = tree[k]
+        if isinstance(v, dict):
+            yield from _paths(v, prefix + (k,))
+        else:
+            yield prefix + (k,), tuple(v)
+
+
+def _draw(key, path, shape):
+    z = jax.random.normal(key, shape, F32)
+    name = path[-1]
+    if name == "w":
+        return z / np.sqrt(shape[-2])
+    if name == "scale":
+        return 1.0 + 0.1 * z
+    return 0.02 * z        # embedding table, biases, norm biases
+
+
+def make_fn(cfg: dict):
+    """key -> params, compiled once per configuration."""
+    return _make_fn(json.dumps(cfg, sort_keys=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _make_fn(cfg_json: str):
+    cfg = json.loads(cfg_json)
+    leaves = list(_paths(arch(cfg).param_shapes(cfg)))
+
+    def build(key):
+        out = {}
+        for i, (path, shape) in enumerate(leaves):
+            node = out
+            for k in path[:-1]:
+                node = node.setdefault(k, {})
+            node[path[-1]] = _draw(jax.random.fold_in(key, i), path, shape)
+        return out
+
+    return jax.jit(build)
+
+
+def check_layout(cfg: dict, program_shapes) -> None:
+    """Raise unless the program's parameter tree has the reference's
+    leaves, shapes and float32 storage."""
+    want = dict(_paths(arch(cfg).param_shapes(cfg)))
+    got = {tuple(getattr(k, "key", k) for k in path): leaf
+           for path, leaf in jax.tree_util.tree_leaves_with_path(
+               program_shapes)}
+    if set(want) != set(got):
+        raise ValueError(f"parameter layout differs: only in the reference "
+                         f"{sorted(set(want) - set(got))}, only in the "
+                         f"program {sorted(set(got) - set(want))}")
+    for path, shape in want.items():
+        leaf = got[path]
+        if tuple(leaf.shape) != shape or leaf.dtype != F32:
+            raise ValueError(f"{'/'.join(path)}: program has {leaf.shape} "
+                             f"{leaf.dtype}, reference {shape} float32")
