@@ -145,6 +145,7 @@ def _recurrent_decode_call(s, p, q, k, v, log_decay, a, b, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="gla_decode_fused" if gated else "linear_decode_fused",
     )(*args)
     return s_new, p_new.reshape(p.shape), og.reshape(bsz, h, dv)
 
@@ -273,6 +274,7 @@ def softmax_decode_fused_pallas(q, k, v, lengths, block_k: int = _BK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="softmax_decode_fused",
     )(lengths.astype(jnp.int32), qg, k, v)
     return o.reshape(b, h, 1, d)
 
@@ -385,6 +387,7 @@ def paged_decode_fused_pallas(q, k_pages, v_pages, page_table, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_fused",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, *kv_args)
     return o.reshape(b, h, 1, d)

@@ -121,6 +121,7 @@ def la_fwd_pallas(q, k, v, a: float, b: float, chunk: int = _CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="la_fwd",
     )(q, k, v)
     return o[:, :, :n], g[:, :, 0, :n]
 
@@ -228,6 +229,7 @@ def la_bwd_pallas(q, k, v, o, g, omega, a: float, b: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="la_bwd_dq",
     )(k, v, om_hat, h_vec)
 
     rev = lambda ti: t - 1 - ti  # noqa: E731 — reverse chunk iteration
@@ -261,6 +263,7 @@ def la_bwd_pallas(q, k, v, o, g, omega, a: float, b: float,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=BWD_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="la_bwd_dkv",
     )(q, k, v, om_hat, h_vec)
 
     return dq[:, :, :n], dk[:, :, :n], dv[:, :, :n]

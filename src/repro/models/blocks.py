@@ -31,9 +31,10 @@ def _ffn_init(key, cfg, dtype, dense_ffn: bool = False):
 
 
 def _ffn_apply(p, cfg, x, compute_dtype, dropless: bool = False):
-    if "router" in p:  # structural marker: MoE FFN
-        return moe_mod.moe_apply(p, cfg, x, compute_dtype, dropless)
-    return mlp_apply(p, x, cfg.mlp_act, compute_dtype), ZERO
+    with jax.named_scope("mlp"):
+        if "router" in p:  # structural marker: MoE FFN
+            return moe_mod.moe_apply(p, cfg, x, compute_dtype, dropless)
+        return mlp_apply(p, x, cfg.mlp_act, compute_dtype), ZERO
 
 
 # ---------------------------------------------------------------------------
@@ -54,7 +55,9 @@ def block_init(key, cfg, dtype=jnp.float32, dense_ffn: bool = False):
 def block_apply(p, cfg, x, positions, compute_dtype=None):
     backend = get_backend(cfg)
     h = norm_apply(p["ln1"], x, cfg.norm)
-    attn_out = backend.apply(p["mixer"], cfg, h, positions, compute_dtype)
+    with jax.named_scope("attention"):
+        attn_out = backend.apply(p["mixer"], cfg, h, positions,
+                                 compute_dtype)
     if backend.fuses_ffn:
         return x + attn_out, ZERO
     if cfg.parallel_residual:
@@ -76,8 +79,9 @@ def block_init_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
 def block_prefill(p, cfg, x, positions, cache, compute_dtype=None):
     backend = get_backend(cfg)
     h = norm_apply(p["ln1"], x, cfg.norm)
-    attn_out, cache = backend.prefill(p["mixer"], cfg, h, positions, cache,
-                                      compute_dtype)
+    with jax.named_scope("attention"):
+        attn_out, cache = backend.prefill(p["mixer"], cfg, h, positions,
+                                          cache, compute_dtype)
     if backend.fuses_ffn:
         return x + attn_out, cache
     if cfg.parallel_residual:
@@ -95,8 +99,9 @@ def block_prefill(p, cfg, x, positions, cache, compute_dtype=None):
 def block_decode(p, cfg, x, position, cache, compute_dtype=None):
     backend = get_backend(cfg)
     h = norm_apply(p["ln1"], x, cfg.norm)
-    attn_out, cache = backend.decode(p["mixer"], cfg, h, position, cache,
-                                     compute_dtype)
+    with jax.named_scope("attention"):
+        attn_out, cache = backend.decode(p["mixer"], cfg, h, position,
+                                         cache, compute_dtype)
     if backend.fuses_ffn:
         return x + attn_out, cache
     if cfg.parallel_residual:

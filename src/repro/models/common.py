@@ -113,10 +113,11 @@ def embed_init(key, vocab: int, d: int, dtype=F32):
 
 
 def embed_lookup(p, tokens, compute_dtype=None):
-    t = p["table"]
-    if compute_dtype is not None:
-        t = t.astype(compute_dtype)
-    return jnp.take(t, tokens, axis=0)
+    with jax.named_scope("embed"):
+        t = p["table"]
+        if compute_dtype is not None:
+            t = t.astype(compute_dtype)
+        return jnp.take(t, tokens, axis=0)
 
 
 def unembed(p, x, compute_dtype=None):

@@ -173,27 +173,31 @@ def forward_hidden(params, cfg, batch):
 
 def _unembed_weight(params, cfg):
     """(d_model, vocab) in f32 for the loss."""
-    if cfg.tie_embeddings:
-        return params["embed"]["table"].astype(F32).T
-    return params["lm_head"]["w"].astype(F32)
+    with jax.named_scope("unembed"):
+        if cfg.tie_embeddings:
+            return params["embed"]["table"].astype(F32).T
+        return params["lm_head"]["w"].astype(F32)
 
 
 def forward_logits(params, cfg, batch):
     """Full logits — small-scale use only (examples, decode)."""
     hidden, _ = forward_hidden(params, cfg, batch)
-    if cfg.tie_embeddings:
-        logits = unembed(params["embed"], hidden.astype(F32))
-    else:
-        logits = dense(params["lm_head"], hidden, F32)
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits
+    with jax.named_scope("unembed"):
+        if cfg.tie_embeddings:
+            logits = unembed(params["embed"], hidden.astype(F32))
+        else:
+            logits = dense(params["lm_head"], hidden, F32)
+        if cfg.logit_softcap:
+            logits = cfg.logit_softcap * jnp.tanh(
+                logits / cfg.logit_softcap)
+        return logits
 
 
 # ---------------------------------------------------------------------------
 # Loss — sequence-chunked cross-entropy (never materializes (B, N, V))
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("unembed")
 def chunked_cross_entropy(hidden, w, labels, mask, chunk: int = 512):
     """hidden: (B, N, d); w: (d, V) f32; labels/mask: (B, N).
 
@@ -396,6 +400,7 @@ def prefill(params, cfg, batch, cache):
     return logits, new_cache
 
 
+@jax.named_scope("unembed")
 def _last_logits(params, cfg, x_last):
     if cfg.tie_embeddings:
         logits = unembed(params["embed"], x_last.astype(F32))

@@ -10,8 +10,8 @@ Contracts:
 
   * Histograms use FIXED log-spaced bucket bounds shared by every
     instrument, so p50/p90/p99 are derivable from any SNAPSHOT (a
-    scraped Prometheus exposition, a metrics JSON artifact) without the
-    raw observations — two snapshots are always mergeable bucket-wise.
+    metrics JSON artifact) without the raw observations — two
+    snapshots are always mergeable bucket-wise.
   * This module is the ONE home for percentile math in the serving
     stack: `Histogram.percentile` (bucketed) and `percentiles` (exact,
     for small in-memory sample lists).  repro.check lint rule
@@ -21,9 +21,8 @@ Contracts:
   * No clocks here: values are observed in seconds by callers that
     stamp via `repro.tune.timer.now()` (obs/events.py).
 
-Exposition: `MetricsRegistry.to_json()` for artifacts and
-`MetricsRegistry.prometheus_text()` (text exposition format 0.0.4) for
-scrapers; both are pure snapshots of host-side state.
+Exposition: `MetricsRegistry.to_json()` for artifacts, a pure snapshot
+of host-side state.
 """
 from __future__ import annotations
 
@@ -222,25 +221,3 @@ class MetricsRegistry:
     def to_json(self) -> dict:
         """The metrics artifact (launch/serve.py --metrics-json)."""
         return {"version": 1, "metrics": self.snapshot()}
-
-    def prometheus_text(self) -> str:
-        """Prometheus text exposition format 0.0.4."""
-        lines: List[str] = []
-        for name, m in sorted(self._metrics.items()):
-            if m.help:
-                lines.append(f"# HELP {name} {m.help}")
-            lines.append(f"# TYPE {name} {m.kind}")
-            if isinstance(m, Histogram):
-                cum = 0
-                for i, c in enumerate(m.counts[:-1]):
-                    cum += c
-                    lines.append(f'{name}_bucket{{le="{m.bounds[i]:.9g}"}}'
-                                 f" {cum}")
-                lines.append(f'{name}_bucket{{le="+Inf"}} {m.total}')
-                lines.append(f"{name}_sum {m.sum:.9g}")
-                lines.append(f"{name}_count {m.total}")
-            else:
-                v = m.value
-                lines.append(f"{name} "
-                             f"{'NaN' if v is None else format(v, '.9g')}")
-        return "\n".join(lines) + "\n"

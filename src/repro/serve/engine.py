@@ -78,6 +78,14 @@ and traced output is token-identical to untraced (pinned by
 tests/test_obs.py).  The only behavioral difference under tracing is a
 block_until_ready per prefill window so window spans measure device
 time, not dispatch time.
+
+PROFILER SPANS (repro.obs.spans, always installed): each phase of a step
+is a host span in the profiler's trace — engine.step (a step marker),
+engine.submit, engine.admit, engine.decode(slots) with its
+engine.decode.wait, engine.emit, engine.prefill(tokens) and
+engine.complete(tokens) with their .wait children.  Every place where
+the host blocks on the chip is inside a `.wait` span; spans are per
+phase, never per slot or token, and add no sync.
 """
 from __future__ import annotations
 
@@ -92,6 +100,7 @@ from repro.configs.base import PagingCfg
 from repro.mixers import get_backend, resolve_backend_name
 from repro.mixers.cache import PagedGLAState, PagedKVCache
 from repro.models import model as mdl
+from repro.obs.spans import span
 from repro.serve import sampling as smp
 from repro.serve.paging import PagedAdmission, PagePool
 from repro.serve.scheduler import AdmissionPolicy, ByteBudget, \
@@ -285,6 +294,7 @@ class Engine:
         self.last_step_budget: Dict[str, int] = {
             "total": self.token_budget, "decode": 0, "prefill": 0}
         self.preemption_count = 0
+        self._step_num = 0               # the profiler's step marker
 
         n = self.num_slots
         self.cache = mdl.init_cache(cfg, n, max_len)
@@ -393,6 +403,10 @@ class Engine:
         return self._requests[rid]
 
     def submit(self, req: Request):
+        with span("engine.submit"):
+            self._submit(req)
+
+    def _submit(self, req: Request):
         if self.tracer is not None:
             self.tracer.request_submitted(req.rid, len(req.prompt),
                                           req.max_new_tokens)
@@ -500,18 +514,21 @@ class Engine:
         remaining budget.  Returns the StepOutputs emitted."""
         tr = self.tracer
         t0 = timer.now() if tr is not None else 0.0
-        budget = TokenBudget(self.token_budget)
-        outputs: List[StepOutput] = []
-        self._admit_and_preempt(outputs)
-        outputs.extend(self._decode_once(budget))
-        self._prefill_round(budget, outputs)
-        self.last_step_budget = {"total": budget.total,
-                                 "decode": budget.decode_tokens,
-                                 "prefill": budget.prefill_tokens}
-        if tr is not None:
-            active = sum(1 for _ in self.scheduler.active())
-            tr.engine_step(t0, active, self.num_slots,
-                           len(self.scheduler.queue))
+        with span("engine.step", step_num=self._step_num):
+            self._step_num += 1
+            budget = TokenBudget(self.token_budget)
+            outputs: List[StepOutput] = []
+            with span("engine.admit"):
+                self._admit_and_preempt(outputs)
+            outputs.extend(self._decode_once(budget))
+            self._prefill_round(budget, outputs)
+            self.last_step_budget = {"total": budget.total,
+                                     "decode": budget.decode_tokens,
+                                     "prefill": budget.prefill_tokens}
+            if tr is not None:
+                active = sum(1 for _ in self.scheduler.active())
+                tr.engine_step(t0, active, self.num_slots,
+                               len(self.scheduler.queue))
         return outputs
 
     def stream(self) -> Iterator[StepOutput]:
@@ -785,8 +802,8 @@ class Engine:
         slot's rows of the batched cache and sample the first token
         from the window's logits.  One dispatch instead of three
         (window + restore + sample), so the step that completes a
-        prefill costs no more than any other window step — the
-        inter-token p99 bound in tests/test_obs.py leans on this.
+        prefill costs no more than any other window step, and the
+        inter-token tail stays flat when a prompt completes.
 
         The batched cache rides in as its batch-dim LEAVES only,
         donated so the scatter is in place.  Donating the full cache
@@ -854,7 +871,8 @@ class Engine:
                 self.cache, carry, self._bdims)
         if tr is not None:
             # span measures device time; the sync changes no values
-            jax.block_until_ready(logits)
+            with span("engine.prefill.wait"):
+                jax.block_until_ready(logits)
             tr.prefill_window(job.req.rid, slot, len(window), t0)
 
     def _set_sampling(self, slot: int, req: Request) -> None:
@@ -891,7 +909,8 @@ class Engine:
             temp, topk, topp)
         if tr is not None:
             # span measures device time; the sync changes no values
-            jax.block_until_ready(toks)
+            with span("engine.complete.wait"):
+                jax.block_until_ready(toks)
             tr.prefill_window(req.rid, slot, len(window), t0)
         self._set_sampling(slot, req)
         del self._jobs[slot]
@@ -902,8 +921,9 @@ class Engine:
             self.next_tokens[slot] = job.resume["next"]
             req.state = RequestState.DECODING
             return None
-        tok = int(toks[0])
-        self._keys[slot] = np.array(key[0])
+        with span("engine.complete.wait"):
+            tok = int(toks[0])
+            self._keys[slot] = np.array(key[0])
         self.next_tokens[slot] = tok
         self.remaining[slot] = req.max_new_tokens - 1
         req.generated.append(tok)
@@ -948,12 +968,15 @@ class Engine:
 
     def _spend_window(self, slot: int, job: _PrefillJob,
                       outputs: List[StepOutput]) -> None:
+        n = len(job.windows[0])
         if len(job.windows) == 1:
-            out = self._run_final_window(slot, job)
+            with span("engine.complete", tokens=n):
+                out = self._run_final_window(slot, job)
             if out is not None:
                 outputs.append(out)
         else:
-            self._run_window(slot, job)
+            with span("engine.prefill", tokens=n):
+                self._run_window(slot, job)
 
     # -- decode --------------------------------------------------------
     def _decode_once(self, budget: TokenBudget) -> List[StepOutput]:
@@ -961,29 +984,33 @@ class Engine:
         if not decoding:
             return []
         budget.spend_decode(len(decoding))
-        toks, self.cache, keys = self._decode(
-            self.params, self.cache,
-            jnp.asarray(self.next_tokens),
-            jnp.asarray(self._keys),
-            jnp.asarray(self._temp),
-            jnp.asarray(self._topk),
-            jnp.asarray(self._topp))
-        nxt = np.asarray(toks)
-        self._keys = np.array(keys)  # writable copy
+        with span("engine.decode", slots=len(decoding)):
+            toks, self.cache, keys = self._decode(
+                self.params, self.cache,
+                jnp.asarray(self.next_tokens),
+                jnp.asarray(self._keys),
+                jnp.asarray(self._temp),
+                jnp.asarray(self._topk),
+                jnp.asarray(self._topp))
+            with span("engine.decode.wait"):
+                nxt = np.asarray(toks)
+                self._keys = np.array(keys)  # writable copy
         tr = self.tracer
         outputs = []
-        for slot, req in decoding:
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            if tr is not None:
-                tr.token_emitted(req.rid, slot)
-            self.next_tokens[slot] = tok
-            self.remaining[slot] -= 1
-            reason = self._finish_reason(slot, tok, self._params_of[slot])
-            if reason:
-                outputs.append(self._finish(slot, req, tok, reason))
-            else:
-                outputs.append(StepOutput(req.rid, tok, req.state))
+        with span("engine.emit"):
+            for slot, req in decoding:
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                if tr is not None:
+                    tr.token_emitted(req.rid, slot)
+                self.next_tokens[slot] = tok
+                self.remaining[slot] -= 1
+                reason = self._finish_reason(slot, tok,
+                                             self._params_of[slot])
+                if reason:
+                    outputs.append(self._finish(slot, req, tok, reason))
+                else:
+                    outputs.append(StepOutput(req.rid, tok, req.state))
         return outputs
 
     # -- lifecycle -----------------------------------------------------

@@ -64,6 +64,7 @@ def filter_logits(logits, top_k, top_p):
     return jnp.where(keep, logits, -jnp.inf)
 
 
+@jax.named_scope("sample")
 def sample(logits, keys, temperature, top_k, top_p):
     """One sampling step for a batch of slots (jit-safe).
 

@@ -14,6 +14,11 @@ Responsibilities (the parts XLA cannot do):
     that does not compile raises at once, and so does any failure that
     reports RESOURCE_EXHAUSTED (a step that does not fit the device
     will not fit on a retry either).
+
+Each step is a `trainer.step` span in the profiler's trace (a step
+marker), over `trainer.batch`, `trainer.dispatch`, `trainer.loss.wait`
+(the one place the loop blocks on the device) and `trainer.record`
+(repro.obs.spans).
 """
 from __future__ import annotations
 
@@ -25,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import store
+from repro.obs.spans import span
 from repro.optim import adamw
 from repro.train.step import build_train_step
 
@@ -124,44 +130,49 @@ class Trainer:
         retries = 0
         target = self.step_idx + num_steps
         while self.step_idx < target:
-            batch_np = self.data_it.batch_at(self.step_idx)
-            batch = {"tokens": batch_np} if isinstance(batch_np, np.ndarray) \
-                else batch_np
-            step_fn = self._executable(batch, self.step_idx)
-            t0 = now()
-            try:
-                if fail_injector is not None:
-                    fail_injector(self.step_idx)
-                out = step_fn(self.params, self.opt_state, batch,
-                              self.step_idx)
-                params, opt_state, metrics = out
-                loss = float(metrics["loss"])
-                if not np.isfinite(loss):
-                    raise FloatingPointError(
-                        f"non-finite loss {loss} at step {self.step_idx}")
-                self.params, self.opt_state = params, opt_state
-            except Exception as e:  # noqa: BLE001 — node/step failure path
-                if "RESOURCE_EXHAUSTED" in str(e):
-                    raise
-                retries += 1
-                log.warning("step %d failed (%s); retry %d/%d from last "
-                            "checkpoint", self.step_idx, e, retries,
-                            self.max_retries)
-                if retries > self.max_retries:
-                    raise
-                if not self.try_restore():
-                    # no checkpoint yet: retry the same step fresh
+            with span("trainer.step", step_num=self.step_idx):
+                with span("trainer.batch"):
+                    batch_np = self.data_it.batch_at(self.step_idx)
+                    batch = {"tokens": batch_np} \
+                        if isinstance(batch_np, np.ndarray) else batch_np
+                step_fn = self._executable(batch, self.step_idx)
+                t0 = now()
+                try:
+                    if fail_injector is not None:
+                        fail_injector(self.step_idx)
+                    with span("trainer.dispatch"):
+                        out = step_fn(self.params, self.opt_state, batch,
+                                      self.step_idx)
+                    params, opt_state, metrics = out
+                    with span("trainer.loss.wait"):
+                        loss = float(metrics["loss"])
+                    if not np.isfinite(loss):
+                        raise FloatingPointError(
+                            f"non-finite loss {loss} at step {self.step_idx}")
+                    self.params, self.opt_state = params, opt_state
+                except Exception as e:  # noqa: BLE001 — node/step failure
+                    if "RESOURCE_EXHAUSTED" in str(e):
+                        raise
+                    retries += 1
+                    log.warning("step %d failed (%s); retry %d/%d from "
+                                "last checkpoint", self.step_idx, e,
+                                retries, self.max_retries)
+                    if retries > self.max_retries:
+                        raise
+                    if not self.try_restore():
+                        # no checkpoint yet: retry the same step fresh
+                        continue
                     continue
-                continue
-            retries = 0
-            dt = now() - t0
-            slow = self.monitor.record(dt)
-            rec = {"step": self.step_idx, "loss": loss, "dt": dt,
-                   "straggler": slow}
-            self.history.append(rec)
-            self.step_idx += 1
-            if self.tc.checkpoint_every and \
-                    self.step_idx % self.tc.checkpoint_every == 0:
-                self.save(blocking=False)
+                retries = 0
+                with span("trainer.record"):
+                    dt = now() - t0
+                    slow = self.monitor.record(dt)
+                    rec = {"step": self.step_idx, "loss": loss, "dt": dt,
+                           "straggler": slow}
+                    self.history.append(rec)
+                    self.step_idx += 1
+                    if self.tc.checkpoint_every and \
+                            self.step_idx % self.tc.checkpoint_every == 0:
+                        self.save(blocking=False)
         self._wait_save()
         return self.history

@@ -77,10 +77,11 @@ def build_train_step(cfg, train_cfg):
     def step(params, opt_state, batch, step_idx):
         grads, metrics = compute_grads(params, batch)
         lr = lr_at(step_idx)
-        params, opt_state, om = adamw.apply(
-            params, grads, opt_state, lr=lr, beta1=train_cfg.beta1,
-            beta2=train_cfg.beta2, weight_decay=train_cfg.weight_decay,
-            grad_clip=train_cfg.grad_clip)
+        with jax.named_scope("adamw"):
+            params, opt_state, om = adamw.apply(
+                params, grads, opt_state, lr=lr, beta1=train_cfg.beta1,
+                beta2=train_cfg.beta2, weight_decay=train_cfg.weight_decay,
+                grad_clip=train_cfg.grad_clip)
         metrics.update(om)
         metrics["lr"] = lr
         return params, opt_state, metrics
@@ -105,10 +106,11 @@ def build_compressed_train_step(cfg, train_cfg, axis_name: str = "data"):
             min_lr=train_cfg.min_learning_rate,
             warmup_steps=train_cfg.warmup_steps,
             total_steps=train_cfg.total_steps)
-        params, opt_state, om = adamw.apply(
-            params, grads, opt_state, lr=lr, beta1=train_cfg.beta1,
-            beta2=train_cfg.beta2, weight_decay=train_cfg.weight_decay,
-            grad_clip=train_cfg.grad_clip)
+        with jax.named_scope("adamw"):
+            params, opt_state, om = adamw.apply(
+                params, grads, opt_state, lr=lr, beta1=train_cfg.beta1,
+                beta2=train_cfg.beta2, weight_decay=train_cfg.weight_decay,
+                grad_clip=train_cfg.grad_clip)
         metrics = {"loss": jax.lax.pmean(l, axis_name), **om}
         return params, opt_state, err, metrics
 

@@ -1,7 +1,7 @@
 """Observability (repro.obs): metrics math against a numpy oracle,
-tracer-disabled engine identity, span completeness, Chrome-trace and
-Prometheus exposition schemas, the head-of-line stall baseline, and the
-BENCH_serve.json schema gate.
+tracer-disabled engine identity, span completeness, the Chrome-trace
+schema, the head-of-line stall pin, and the BENCH_serve.json schema
+gate.
 
 The load-bearing pins:
 
@@ -10,14 +10,12 @@ The load-bearing pins:
   * Histogram.percentile (bucketed) brackets the exact inverted-CDF
     percentile within one BUCKET_RATIO — the snapshot-only derivation
     the metrics artifact relies on;
-  * a long prompt's chunked prefill stalls a co-resident request's
-    decode, so inter-token p99 >> p50 — the baseline number the
-    scheduler roadmap item is judged against.
+  * a long prompt's chunked prefill no longer stalls a co-resident
+    request's decode: its windows interleave with that request's
+    tokens, at most one window between two tokens.
 """
-import gc
 import json
 import math
-import re
 
 import jax
 import numpy as np
@@ -112,31 +110,6 @@ def test_counter_gauge_registry():
     assert doc["metrics"]["c"] == {"kind": "counter", "value": 3.5}
 
 
-def test_prometheus_exposition_format():
-    m = MetricsRegistry()
-    m.counter("serve_tokens_total", "tokens").inc(5)
-    m.gauge("serve_slots_active")  # never set -> NaN
-    h = m.histogram("serve_ttft_seconds", "ttft")
-    h.observe(0.01)
-    h.observe(0.02)
-    text = m.prometheus_text()
-    assert "# TYPE serve_tokens_total counter\n" in text
-    assert "\nserve_tokens_total 5\n" in text
-    assert "\nserve_slots_active NaN\n" in text
-    # cumulative le-buckets, +Inf terminal, sum/count
-    assert re.search(r'serve_ttft_seconds_bucket\{le="\+Inf"\} 2\n', text)
-    assert re.search(r"serve_ttft_seconds_sum 0\.03\b", text)
-    assert re.search(r"serve_ttft_seconds_count 2\n", text)
-    les = [float(x) for x in
-           re.findall(r'serve_ttft_seconds_bucket\{le="([\d.e+-]+)"\}',
-                      text)]
-    assert les == sorted(les)
-    counts = [int(x) for x in
-              re.findall(r'serve_ttft_seconds_bucket\{le="[^"]+"\} (\d+)',
-                         text)]
-    assert counts == sorted(counts)  # cumulative
-
-
 # ---------------------------------------------------------------------------
 # tracer vs engine: identity, spans, lifecycle
 # ---------------------------------------------------------------------------
@@ -229,70 +202,44 @@ def test_step_output_timestamps_and_finish(linear_setup):
 
 
 def test_decode_stall_inter_token_p99(linear_setup):
-    """The head-of-line scenario PR 9 pinned as a stall (inter-token
-    p99 > 5x p50 under the FIFO scheduler, which ran ALL of a long
-    prompt's prefill windows inside one step): under scheduler v2 the
-    token budget interleaves at most ~one prefill window with each
-    decode step, so the mid-stream long-prompt injection no longer
-    blows up the short request's tail — p99 stays within 2x p50.
+    """The head-of-line scenario that used to stall (the FIFO scheduler
+    ran ALL of a long prompt's prefill windows inside one step, so a
+    co-resident request's inter-token p99 was many times its p50):
+    under scheduler v2 the token budget interleaves at most one
+    prefill window with each decode step.
 
-    Timing-test hygiene: Python GC is paused over the measured steps
-    (a gen-2 collection costs a few ms — several inter-token periods
-    at this model size), and the bound gets two attempts.  A genuine
-    head-of-line stall is STRUCTURAL — the admission step runs every
-    window back-to-back, several ms extra on one delta, every attempt
-    — while a stray OS/scheduler hiccup is transient."""
+    The property is read from the ORDER of the tracer's stamps, not
+    from their differences, so a loaded host cannot fail it: between
+    any two consecutive tokens of the short request at most one window
+    of the long prompt starts, and its 6 windows interleave with at
+    least 4 of those tokens."""
     cfg, params = linear_setup
-
-    def attempt():
-        tr = ServeTracer()
-        eng = Engine(cfg, params, max_slots=2, max_len=64, eos_id=-1,
-                     prefill_chunk=5, tracer=tr)
-        # warm every jitted program this workload will hit (the
-        # 5-token mid-prompt window, the fused 5- and 1-token FINAL
-        # windows, the batched decode) so the measured deltas see
-        # SCHEDULING, not one-time compile spikes
-        eng.submit(Request(rid=99, prompt=list(range(3, 9)),
-                           max_new_tokens=2))     # windows [5, 1]
-        eng.submit(Request(rid=98, prompt=list(range(3, 13)),
-                           max_new_tokens=2))     # windows [5, 5]
-        eng.run()
-        gc.collect()
-        gc.disable()
-        try:
-            eng.submit(Request(rid=0, prompt=list(range(3, 9)),
-                               max_new_tokens=16))
-            for _ in range(8):      # rid 0 decodes at steady cadence
-                eng.step()
-            eng.submit(Request(rid=1, prompt=list(range(3, 33)),
-                               max_new_tokens=4))   # 6 prefill windows
-            while eng.scheduler.has_work():
-                eng.step()
-        finally:
-            gc.enable()
-        rec = tr.records()[0]
-        assert rec.rid == 0 and rec.closed
-        deltas = rec.inter_token_s
-        assert len(deltas) == 15
-        # the long prompt still ran all its windows — spread across
-        # steps (token-interleaved), not packed into one
-        long_rec = tr.records()[1]
-        assert len(long_rec.prefill_windows) == 6
-        # ... and the short request kept emitting tokens BETWEEN those
-        # windows — the interleaving itself, not just its tail effect
-        w0 = long_rec.prefill_windows[0][0]
-        w1 = long_rec.prefill_windows[-1][1]
-        interleaved = [t for t in rec.token_ts if w0 < t < w1]
-        assert len(interleaved) >= 4, (len(interleaved),
-                                       "prefill ran as one "
-                                       "uninterrupted block — no "
-                                       "token interleaving")
-        return percentiles(deltas, (50, 99))
-
-    ps = attempt()
-    if ps[99] > 2 * ps[50]:
-        ps = attempt()
-    assert ps[99] <= 2 * ps[50], (ps, "head-of-line stall regressed")
+    tr = ServeTracer()
+    eng = Engine(cfg, params, max_slots=2, max_len=64, eos_id=-1,
+                 prefill_chunk=5, tracer=tr)
+    eng.submit(Request(rid=0, prompt=list(range(3, 9)),
+                       max_new_tokens=16))
+    for _ in range(8):      # rid 0 decodes alone
+        eng.step()
+    eng.submit(Request(rid=1, prompt=list(range(3, 33)),
+                       max_new_tokens=4))   # 6 prefill windows
+    while eng.scheduler.has_work():
+        eng.step()
+    short, long_rec = tr.records()
+    assert short.rid == 0 and short.closed and long_rec.rid == 1
+    assert len(short.inter_token_s) == 15
+    starts = [w[0] for w in long_rec.prefill_windows]
+    assert len(starts) == 6
+    ts = list(short.token_ts)
+    for a, b in zip(ts, ts[1:]):
+        between = sum(1 for t in starts if a < t < b)
+        assert between <= 1, (between, "windows ran back to back "
+                              "between two tokens: head-of-line stall")
+    end = long_rec.prefill_windows[-1][1]
+    interleaved = [t for t in ts if starts[0] < t < end]
+    assert len(interleaved) >= 4, (len(interleaved), "prefill ran as one "
+                                   "uninterrupted block — no token "
+                                   "interleaving")
 
 
 def test_rejected_request_traced(linear_setup):
