@@ -81,7 +81,7 @@ time, not dispatch time.
 
 PROFILER SPANS (repro.obs.spans, always installed): each phase of a step
 is a host span in the profiler's trace — engine.step (a step marker),
-engine.submit, engine.admit, engine.decode(slots) with its
+engine.submit, engine.admit, engine.decode(slots, sampled) with its
 engine.decode.wait, engine.emit, engine.prefill(tokens) and
 engine.complete(tokens) with their .wait children.  Every place where
 the host blocks on the chip is inside a `.wait` span; spans are per
@@ -984,7 +984,8 @@ class Engine:
         if not decoding:
             return []
         budget.spend_decode(len(decoding))
-        with span("engine.decode", slots=len(decoding)):
+        sampled = sum(1 for slot, _ in decoding if self._temp[slot] > 0)
+        with span("engine.decode", slots=len(decoding), sampled=sampled):
             toks, self.cache, keys = self._decode(
                 self.params, self.cache,
                 jnp.asarray(self.next_tokens),

@@ -39,29 +39,73 @@ class SamplingParams:
     seed: Optional[int] = None
 
 
+def _key(x):
+    """f32 -> int32 keys in the same order (for non-NaN x): a negative
+    float's magnitude bits are flipped, so larger magnitudes key lower."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ 0x7FFFFFFF, b)
+
+
+def _value(k):
+    """The f32 whose `_key` is k (the map is its own inverse)."""
+    return jax.lax.bitcast_convert_type(jnp.where(k < 0, k ^ 0x7FFFFFFF, k),
+                                        F32)
+
+
 def filter_logits(logits, top_k, top_p):
     """Mask logits outside the per-row top-k / nucleus (top-p) sets.
 
     logits: (B, V) f32; top_k: (B,) int32 (<= 0 disables); top_p: (B,)
     f32 (>= 1 disables).  Returns (B, V) with filtered entries at -inf.
-    The top-1 token always survives, so the filters can never produce an
-    all--inf row.
+
+    A row keeps every logit >= its threshold t, the smallest logit for
+    which both hold:
+      * nucleus: the probability (softmax at temperature 1) of the logits
+        > t is below top_p.  The tokens before t in descending order hold
+        less than p, so the token whose mass carries the sum past p is
+        kept, and so is every tie of it;
+      * top-k: fewer than top_k logits are > t, so the k-th largest and
+        its ties are kept.
+    Both only get easier as t rises, so t is found without a sort, by
+    bisection over the row's order-preserving int32 keys (`_key`): 32
+    passes, each one reduction over (B, V) that reads the logits once,
+    halve the bracket (min key - 1, max key], under 2**32 keys wide, to
+    one key.  A pass compares floats (logits > the key's value), so -0.0
+    and +0.0, adjacent keys, count as one value, as in the final `>=`.
+    top_p = 0 leaves t at the row's max, so the top-1 token always
+    survives and no row is all -inf.  The top-k count is traced only when
+    some row asks for it.
     """
     v = logits.shape[-1]
-    desc = jnp.sort(logits, axis=-1)[..., ::-1]                    # (B, V)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    # nucleus at mass < p: the unnormalised mass exp(l - m) against p * Z
+    p_z = (jnp.where(top_p >= 1.0, 2.0, top_p)
+           * jnp.sum(jnp.exp(logits - m), axis=-1))
     k_eff = jnp.where(top_k > 0, jnp.minimum(top_k, v), v)
-    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=-1)  # (B, 1)
-    keep = logits >= kth
-    # nucleus: keep tokens while the EXCLUSIVE cumulative mass < p, so
-    # the first token is always kept and mass crosses p inclusively
-    probs = jax.nn.softmax(desc, axis=-1)
-    excl = jnp.cumsum(probs, axis=-1) - probs
-    p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)                    # disable
-    kept_sorted = excl < p_eff[:, None]
-    kept_sorted = kept_sorted.at[..., 0].set(True)  # top-1 survives p=0
-    thresh = jnp.min(jnp.where(kept_sorted, desc, jnp.inf), axis=-1)
-    keep = keep & (logits >= thresh[:, None])
-    return jnp.where(keep, logits, -jnp.inf)
+
+    def search(with_k):
+        def body(_, bracket):
+            lo, hi = bracket            # the answer lies in (lo, hi]
+            # floor((lo + hi) / 2) without int32 overflow
+            mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)
+            mid = jnp.where(mid == lo, hi, mid)
+            above = logits > _value(mid)[:, None]
+            mass = jnp.where(above, jnp.exp(logits - m), 0.0)
+            if with_k:      # one variadic reduction: one read of logits
+                mass, count = jax.lax.reduce(
+                    (mass, above.astype(jnp.int32)), (F32(0), jnp.int32(0)),
+                    lambda a, b: (a[0] + b[0], a[1] + b[1]), (1,))
+                ok = (mass < p_z) & (count < k_eff)
+            else:
+                ok = jnp.sum(mass, axis=-1) < p_z
+            return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
+
+        bracket = (_key(jnp.min(logits, axis=-1)) - 1, _key(m[:, 0]))
+        return jax.lax.fori_loop(0, 32, body, bracket)[1]
+
+    thresh = jax.lax.cond(jnp.any(k_eff < v), lambda: search(True),
+                          lambda: search(False))
+    return jnp.where(logits >= _value(thresh)[:, None], logits, -jnp.inf)
 
 
 @jax.named_scope("sample")

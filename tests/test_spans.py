@@ -3,7 +3,8 @@
   * under `jax.profiler`, an Engine and a Trainer write every documented
     `engine.*` / `trainer.*` span into the trace, each nested in its
     documented parent, with counts that agree with the engine's own
-    per-step budget;
+    per-step budget, and an `engine.decode` span counts the slots that
+    sample;
   * the lowered decode, prefill, completion and train programs carry the
     model's named scopes in their op-name metadata;
   * every Pallas kernel of the linear and decode families carries its
@@ -22,6 +23,7 @@ from helpers import backend_cfg
 from repro.models import model as mdl
 from repro.obs import ServeTracer, span
 from repro.serve.engine import Engine, Request
+from repro.serve.sampling import SamplingParams
 
 # span -> the span it opens inside (None: opened by the caller)
 ENGINE_PARENTS = {
@@ -125,6 +127,29 @@ def test_engine_spans_nest_and_count(linear_setup, tmp_path):
         inner = collections.Counter(s[2] for s in spans
                                     if start <= s[0] and s[1] <= end)
         assert inner["engine.decode"] <= 1 and inner["engine.emit"] <= 1
+
+
+def test_decode_span_counts_sampled_slots(linear_setup, tmp_path):
+    """`sampled` counts the decoding slots with temperature > 0: those
+    for which the decode program filters and draws, not takes argmax."""
+    cfg, params = linear_setup
+    hot = SamplingParams(temperature=0.8, top_p=0.9, seed=1)
+
+    def decode_spans(sampling, trace_dir):
+        eng = Engine(cfg, params, max_slots=3, max_len=64, eos_id=-1,
+                     prefill_chunk=16)
+        for rid, (sp, n) in enumerate(zip(sampling, (2, 5, 5))):
+            eng.submit(Request(rid=rid, prompt=list(range(3, 7)),
+                               max_new_tokens=n, sampling=sp))
+        spans = _profiled(tmp_path / trace_dir, eng.run)
+        return [(s[3]["slots"], s[3]["sampled"]) for s in spans
+                if s[2] == "engine.decode"]
+
+    # the greedy request (2 tokens) leaves after its first decode step
+    assert decode_spans([SamplingParams(), hot, hot], "mixed") == \
+        [(3, 2)] + [(2, 2)] * 3
+    assert decode_spans([SamplingParams()] * 3, "greedy") == \
+        [(3, 0)] + [(2, 0)] * 3
 
 
 def test_trainer_spans_nest_and_count(tmp_path):
