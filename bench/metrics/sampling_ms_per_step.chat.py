@@ -1,8 +1,9 @@
 """sampling_ms_per_step.chat: device time of the operations in the
-sampler's named scope (`sample`: the top-p sort, softmax, cumulative
-sum and draw of serve/sampling.py) in the traced window, per decode
-step the engine ran there (its `engine.decode` spans), in ms.  The
-completion program's one-row samples count too; they are a small part."""
+sampler's named scope (`sample`: the top-p and top-k threshold search,
+the Gumbel draw and the argmax of serve/sampling.py) in the traced
+window, per decode step the engine ran there (its `engine.decode`
+spans), in ms.  The completion program's one-row samples count too;
+they are a small part."""
 from bench import progtrace
 
 

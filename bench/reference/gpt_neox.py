@@ -10,6 +10,19 @@ from __future__ import annotations
 from bench.reference import common as c
 
 
+def program_fields(cfg: dict) -> dict:
+    """The program's ModelConfig fields that this block implies."""
+    if cfg["hidden_act"] != "gelu_pytorch_tanh":
+        raise ValueError("the program's GELU is the tanh form, not "
+                         f"{cfg['hidden_act']!r}")
+    # no biases: a departure that the configuration file lists
+    return dict(num_kv_heads=cfg["num_attention_heads"], qkv_bias=False,
+                mlp_act="gelu", norm="layernorm",
+                parallel_residual=cfg["use_parallel_residual"],
+                rope_kind="partial", rope_fraction=cfg["rotary_pct"],
+                rope_theta=float(cfg["rotary_emb_base"]))
+
+
 def param_shapes(cfg: dict) -> dict:
     d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
     n = cfg["num_hidden_layers"]

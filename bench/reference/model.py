@@ -18,7 +18,35 @@ F32 = jnp.float32
 
 
 def arch(cfg: dict):
-    return importlib.import_module("bench.reference." + cfg["model_type"])
+    """The module of the configuration's architecture,
+    bench/reference/<model_type>.py.  Besides `program_fields`,
+    `param_shapes`, `layer`, `final_norm` and `unembedding` it may give
+    `stack` (the layers from the embedding to the final norm; default
+    `scan_blocks`), `loss_terms` with `loss_weight` (a term added to the
+    loss; default none), `draw_scale` (bench/weights.py) and `smoke_keys`
+    (bench/tests/tiny.py)."""
+    name = "bench.reference." + cfg["model_type"]
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise ValueError(f"no architecture module for model_type "
+                         f"{cfg['model_type']!r}: add bench/reference/"
+                         f"{cfg['model_type']}.py") from None
+
+
+def scan_blocks(mat, params, x, positions, cfg: dict):
+    """The default stack: the architecture's `layer` over the stacked
+    `params["blocks"]`, each layer recomputed in the backward."""
+    a = arch(cfg)
+
+    @jax.checkpoint
+    def body(x, lp):
+        return a.layer(mat, lp, x, positions, cfg), None
+
+    x, _ = jax.lax.scan(body, x, params["blocks"])
+    return x
 
 
 def hidden(params, cfg: dict, tokens, precision: str = "f32"):
@@ -27,12 +55,7 @@ def hidden(params, cfg: dict, tokens, precision: str = "f32"):
     mat = c.Mat(precision)
     positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     x = params["embed"]["table"].astype(F32)[tokens]
-
-    @jax.checkpoint
-    def body(x, lp):
-        return a.layer(mat, lp, x, positions, cfg), None
-
-    x, _ = jax.lax.scan(body, x, params["blocks"])
+    x = getattr(a, "stack", scan_blocks)(mat, params, x, positions, cfg)
     return a.final_norm(params["ln_f"], x, cfg)
 
 
@@ -46,7 +69,9 @@ def logits_at(params, cfg: dict, tokens, rows, precision: str = "f32"):
 
 def loss(params, cfg: dict, tokens, precision: str = "f32"):
     """Mean next-token cross-entropy over a batch (B, N): every position
-    but the last predicts the token after it."""
+    but the last predicts the token after it.  Where the architecture
+    gives `loss_terms`, their mean over the rows is added with the
+    weight its `loss_weight(cfg)` states."""
     a = arch(cfg)
     mat = c.Mat(precision)
     w, tied = a.unembedding(params)
@@ -57,7 +82,11 @@ def loss(params, cfg: dict, tokens, precision: str = "f32"):
         h = hidden(params, cfg, row, precision)
         labels = jnp.concatenate([row[1:], row[:1]])
         total = total + c.cross_entropy_rows(mat, h, w, labels, mask, tied)
-    return total / (tokens.shape[0] * (n - 1))
+    ce = total / (tokens.shape[0] * (n - 1))
+    if not hasattr(a, "loss_terms"):
+        return ce
+    terms = sum(a.loss_terms(mat, params, row, cfg) for row in tokens)
+    return ce + a.loss_weight(cfg) * terms / tokens.shape[0]
 
 
 def lr_at(step, opt: dict):

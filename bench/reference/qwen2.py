@@ -10,6 +10,17 @@ from __future__ import annotations
 from bench.reference import common as c
 
 
+def program_fields(cfg: dict) -> dict:
+    """The program's ModelConfig fields that this block implies."""
+    if cfg["hidden_act"] != "silu":
+        raise ValueError("qwen2 runs a SwiGLU MLP, not "
+                         f"{cfg['hidden_act']!r}")
+    return dict(num_kv_heads=cfg["num_key_value_heads"], qkv_bias=True,
+                mlp_act="swiglu", norm="rmsnorm", parallel_residual=False,
+                rope_kind="standard", rope_fraction=1.0,
+                rope_theta=float(cfg["rope_theta"]))
+
+
 def param_shapes(cfg: dict) -> dict:
     d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
     n = cfg["num_hidden_layers"]

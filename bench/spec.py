@@ -2,8 +2,10 @@
 
 A cell names a configuration (`bench/configs/<config>.json`) and a
 traffic mix (`bench/traffic/<traffic>.json`); a per-layer metric is read
-by `bench/metrics/<metric>.py`.  Nothing here knows a cell by name, so a
-later cell or metric is new files and a new entry, never an edit.
+by `bench/metrics/<metric>.py`; a configuration's `model_type` names the
+module that knows its architecture (`bench/reference/<model_type>.py`).
+Nothing here knows a cell or an architecture by name, so a later cell,
+metric or architecture is new files and a new entry, never an edit.
 """
 from __future__ import annotations
 
@@ -70,43 +72,27 @@ def reader(metric: str):
     return mod.read
 
 
-def _qwen2(c: dict) -> dict:
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"qwen2 runs a SwiGLU MLP, not {c['hidden_act']!r}")
-    return dict(num_kv_heads=c["num_key_value_heads"], qkv_bias=True,
-                mlp_act="swiglu", norm="rmsnorm", parallel_residual=False,
-                rope_kind="standard", rope_fraction=1.0,
-                rope_theta=float(c["rope_theta"]))
-
-
-def _gpt_neox(c: dict) -> dict:
-    if c["hidden_act"] != "gelu_pytorch_tanh":
-        raise ValueError("the program's GELU is the tanh form, not "
-                         f"{c['hidden_act']!r}")
-    # no biases: a departure that the configuration file lists
-    return dict(num_kv_heads=c["num_attention_heads"], qkv_bias=False,
-                mlp_act="gelu", norm="layernorm",
-                parallel_residual=c["use_parallel_residual"],
-                rope_kind="partial", rope_fraction=c["rotary_pct"],
-                rope_theta=float(c["rotary_emb_base"]))
-
-
-ARCHITECTURES = {"qwen2": _qwen2, "gpt_neox": _gpt_neox}
-
-
 def model_config(cfgjson: dict):
-    """The repro ModelConfig that the configuration file runs, derived
-    from its published keys by the mapping of its `model_type`."""
-    from repro.configs.base import LACfg, ModelConfig
+    """The repro ModelConfig that the configuration file runs: the
+    fields every configuration shares, and those that the mapping of its
+    architecture (`program_fields` of bench/reference/<model_type>.py)
+    derives from its published keys."""
+    from bench.reference.model import arch
+    from repro.configs.base import LACfg, MLACfg, ModelConfig, MoECfg, \
+        SSMCfg
     c = cfgjson
     att = c["attention"]
     la = LACfg(a=att["a"], b=att["b"], normalize_qk=att["normalize_qk"],
                chunk=att["chunk"], backend=att["kernel"])
+    fields = {"family": "dense", **arch(c).program_fields(c)}
+    for key, kind in (("moe", MoECfg), ("mla", MLACfg), ("ssm", SSMCfg)):
+        if fields.get(key) is not None:
+            fields[key] = kind(**fields[key])
     return ModelConfig(
-        name=c["name"], family="dense", attention_backend=att["kind"], la=la,
+        name=c["name"], attention_backend=att["kind"], la=la,
         num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
         num_heads=c["num_attention_heads"], d_ff=c["intermediate_size"],
         vocab_size=c["vocab_size"], tie_embeddings=c["tie_word_embeddings"],
         param_dtype=c["precision"]["params"],
         compute_dtype=c["precision"]["compute"],
-        remat=c.get("remat", True), **ARCHITECTURES[c["model_type"]](c))
+        remat=c.get("remat", True), **fields)

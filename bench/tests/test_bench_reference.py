@@ -11,13 +11,13 @@ from bench import spec, weights
 from bench.reference import model as ref
 from bench.tests.tiny import tiny
 
-CONFIGS = ["pythia-1.4b-la-6l", "qwen2.5-3b-la"]
+CONFIGS = [c["name"] for c in spec.benchmark()["configs"]]
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_logits_match_program(name):
     from repro.models import model as mdl
-    cfg = tiny(name)
+    cfg = tiny(spec.config(name))
     mc = spec.model_config(cfg)
     weights.check_layout(cfg, jax.eval_shape(
         lambda: mdl.init_params(mc, jax.random.PRNGKey(0))))
@@ -35,7 +35,7 @@ def test_reference_logits_match_program(name):
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_loss_matches_program(name):
     from repro.models import model as mdl
-    cfg = tiny(name)
+    cfg = tiny(spec.config(name))
     mc = spec.model_config(cfg)
     params = weights.make_fn(cfg)(weights.seed_key(7))
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 32), 0, 256)
@@ -51,7 +51,7 @@ def test_reference_loss_matches_program(name):
 
 
 def test_fp8_control_departs_from_reference():
-    cfg = tiny("qwen2.5-3b-la")
+    cfg = tiny(spec.config("qwen2.5-3b-la"))
     params = weights.make_fn(cfg)(weights.seed_key(3))
     tokens = jax.random.randint(jax.random.PRNGKey(3), (32,), 0, 256)
     f32, fp8 = (jax.jit(lambda p, t, prec=prec: ref.logits_at(
@@ -62,7 +62,7 @@ def test_fp8_control_departs_from_reference():
 
 
 def test_weights_are_seeded():
-    cfg = tiny("pythia-1.4b-la-6l")
+    cfg = tiny(spec.config("pythia-1.4b-la-6l"))
     fn = weights.make_fn(cfg)
     a, b = fn(weights.seed_key(11)), fn(weights.seed_key(11))
     c = fn(weights.seed_key(12))
